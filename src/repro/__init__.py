@@ -1,2 +1,1 @@
 """FedVision reproduction: federated visual/LM training on jax+Pallas."""
-from repro import _jax_compat  # noqa: F401 — uniform jax API across versions

@@ -35,26 +35,23 @@ Batch = Any
 SCORE_THRESH = 0.05
 
 
-def decode_predictions(
+def detection_candidates(
     cfg,
     params,
     images: jax.Array,
     *,
     max_detections: int = 64,
-    score_thresh: float = SCORE_THRESH,
-    nms_iou: float = 0.5,
-    interpret: bool = True,
 ) -> dict[str, jax.Array]:
-    """images (B, H, W, 3) -> fixed-size detections per image.
+    """images (B, H, W, 3) -> the K = max_detections top-scoring decoded
+    boxes per image, before NMS.
 
     Returns {"boxes" (B, K, 4) center-format, "scores" (B, K) descending,
-    "cls" (B, K) int32, "valid" (B, K) 0/1 f32} with K = max_detections.
-    All three scales are decoded, flattened, top-K'd by conf * max class
-    prob, then suppressed by ONE batched Pallas NMS launch. NMS is
-    class-aware via the coordinate-offset trick: each class's boxes are
-    x-shifted by a stride wider than any box extent in the batch (decoded
-    w/h can blow past [0, 1] — up to anchor * e^6 — so the stride is
-    computed from the boxes, not assumed from normalized coordinates).
+    "cls" (B, K) int32, "shifted" (B, K, 4)}: all three scales are decoded,
+    flattened and top-K'd by conf * max class prob. ``shifted`` is what
+    class-aware NMS runs on (the coordinate-offset trick): each class's
+    boxes are x-shifted by a stride wider than any box extent in the image
+    (decoded w/h can blow past [0, 1] — up to anchor * e^6 — so the stride
+    is computed from the boxes, not assumed from normalized coordinates).
     """
     outs = yolov3.forward(params, images, cfg)
     boxes, scores, labels = [], [], []
@@ -87,10 +84,28 @@ def decode_predictions(
     shifted = top_boxes.at[..., 0].add(
         top_labels.astype(jnp.float32) * stride[:, None]
     )
-    keep = ops.nms(
-        shifted, top_scores, iou_thresh=nms_iou, score_thresh=score_thresh, interpret=interpret
-    )
-    return {"boxes": top_boxes, "scores": top_scores, "cls": top_labels, "valid": keep}
+    return {"boxes": top_boxes, "scores": top_scores, "cls": top_labels, "shifted": shifted}
+
+
+def decode_predictions(
+    cfg,
+    params,
+    images: jax.Array,
+    *,
+    max_detections: int = 64,
+    score_thresh: float = SCORE_THRESH,
+    nms_iou: float = 0.5,
+) -> dict[str, jax.Array]:
+    """images (B, H, W, 3) -> fixed-size detections per image.
+
+    Returns {"boxes" (B, K, 4) center-format, "scores" (B, K) descending,
+    "cls" (B, K) int32, "valid" (B, K) 0/1 f32} with K = max_detections:
+    the :func:`detection_candidates` suppressed by ONE batched, class-aware
+    Pallas NMS launch.
+    """
+    cand = detection_candidates(cfg, params, images, max_detections=max_detections)
+    keep = ops.nms(cand.pop("shifted"), cand["scores"], iou_thresh=nms_iou, score_thresh=score_thresh)
+    return {**cand, "valid": keep}
 
 
 def match_detections(
@@ -100,7 +115,6 @@ def match_detections(
     gt_valid: jax.Array,
     *,
     iou_thresh: float = 0.5,
-    interpret: bool = True,
 ) -> jax.Array:
     """Greedy score-ordered matching -> per-detection TP flags (B, K) f32.
 
@@ -111,7 +125,7 @@ def match_detections(
     iff its best same-class, still-unmatched, valid GT reaches iou_thresh
     (each GT matches at most one detection — COCO/VOC greedy semantics).
     """
-    iou = ops.pairwise_iou(pred["boxes"], gt_boxes, interpret=interpret)  # (B, K, G)
+    iou = ops.pairwise_iou(pred["boxes"], gt_boxes)  # (B, K, G)
 
     def per_image(iou_i, pcls_i, pvalid_i, gcls_i, gvalid_i):
         def step(matched, k):
@@ -175,16 +189,13 @@ def evaluate_detections(
     n_classes: int,
     *,
     iou_thresh: float = 0.5,
-    interpret: bool = True,
 ) -> dict[str, jax.Array]:
     """One population's detection quality: {"ap" (n_classes,), "map" ()}.
 
     Leading dim of every array is the image axis; matching runs once, AP
     pools every image's detections (mAP@iou_thresh, default 0.5).
     """
-    tp = match_detections(
-        pred, gt_boxes, gt_cls, gt_valid, iou_thresh=iou_thresh, interpret=interpret
-    )
+    tp = match_detections(pred, gt_boxes, gt_cls, gt_valid, iou_thresh=iou_thresh)
     n_gt = jnp.sum(
         jax.nn.one_hot(gt_cls, n_classes, dtype=jnp.float32) * gt_valid[..., None],
         axis=(0, 1),
@@ -203,7 +214,6 @@ def build_evaluator(
     score_thresh: float = SCORE_THRESH,
     nms_iou: float = 0.5,
     match_iou: float = 0.5,
-    interpret: bool = True,
 ):
     """Jitted federated evaluator: (params, eval_batch) -> mAP tree.
 
@@ -223,15 +233,12 @@ def build_evaluator(
         flat = lambda x: x.reshape((C * B,) + x.shape[2:])
         pred = decode_predictions(
             cfg, params, flat(images),
-            max_detections=max_detections, score_thresh=score_thresh,
-            nms_iou=nms_iou, interpret=interpret,
+            max_detections=max_detections, score_thresh=score_thresh, nms_iou=nms_iou,
         )
         gt_boxes = flat(batch["gt_boxes"]).astype(jnp.float32)
         gt_cls = flat(batch["gt_cls"]).astype(jnp.int32)
         gt_valid = flat(batch["gt_valid"]).astype(jnp.float32)
-        tp = match_detections(
-            pred, gt_boxes, gt_cls, gt_valid, iou_thresh=match_iou, interpret=interpret
-        )
+        tp = match_detections(pred, gt_boxes, gt_cls, gt_valid, iou_thresh=match_iou)
         gt_hist = jax.nn.one_hot(gt_cls, n_classes, dtype=jnp.float32) * gt_valid[..., None]
 
         def client_ap(scores, tps, valids, clss, n_gt):

@@ -281,7 +281,6 @@ def grouped_weighted_mean(
     mask: jax.Array | None = None,
     *,
     impl: str = "ref",
-    interpret: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
     """Per-group renormalized Eq. 5 — the hierarchical inner reduce.
 
@@ -309,7 +308,7 @@ def grouped_weighted_mean(
     if impl == "pallas":
         from repro.kernels import pack as _pk  # deferred: kernels are optional here
 
-        return _pk.grouped_reduce(packed, wn, interpret=interpret), den
+        return _pk.grouped_reduce(packed, wn), den
     xg = packed.astype(jnp.float32).reshape(ngroups, G, N)
     if G > CHAIN_MAX_CLIENTS:
         return jnp.einsum("gi,gin->gn", wn, xg), den
@@ -326,7 +325,6 @@ def masked_bucket_mean(
     mask: jax.Array | None = None,
     *,
     impl: str = "ref",
-    interpret: bool = True,
 ) -> tuple[jax.Array, jax.Array]:
     """Weighted mean over clients under a per-(client, bucket) mask.
 
@@ -358,7 +356,7 @@ def masked_bucket_mean(
         # and the out-of-window ids would silently one-hot to zero
         num, den = _pk.packed_bucket_reduce(
             packed, wmask, ids, mask,
-            interpret=interpret, bucket_tile=bucket_tile_bound(spec, _pk.BLOCK_N),
+            bucket_tile=bucket_tile_bound(spec, _pk.BLOCK_N),
         )
         return num / jnp.maximum(den, 1e-12), den_b
     wn = wm / jnp.maximum(den_b, 1e-12)[None, :]

@@ -47,6 +47,7 @@ sharding specs used by the launcher and the dry-run.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable
 
 import numpy as np
@@ -74,7 +75,7 @@ class FedConfig:
     data_axis: str | None = "data"  # within-client data-parallel axis
     round_idx_static: int = 0  # static_topn: trace-time round phase
     microbatches: int = 1  # grad-accumulation splits of each local step
-    agg_impl: str = "ref"  # ref (jnp) | pallas (packed kernel, interpret on CPU)
+    agg_impl: str = "ref"  # ref (jnp) | pallas (packed kernels; mode from the backend)
     quant_block: int = 1024  # quant8: elements per int8 scale block
     server_lr: float = 1.0  # fedavgm/fedadam server step (fedadam wants ~0.01-0.1)
     server_momentum: float = 0.9  # fedavgm momentum / fedadam b1
@@ -440,19 +441,34 @@ def _local_training(cfg: ArchConfig, fed: FedConfig, optimizer: Optimizer):
     return local_train, gated_local_train
 
 
-def _train_clients_fn(fed: FedConfig, local_train, gated_local_train):
+def _train_clients_fn(fed: FedConfig, local_train, gated_local_train, mesh=None):
     """full/masked dispatch over materialized-or-view param trees; compact's
-    gather/scatter stays with each engine (it moves state rows)."""
+    gather/scatter stays with each engine (it moves state rows).
 
-    def train_clients(params, opt, batch, mask):
+    With a mesh whose client axis has more than one shard, training runs
+    shard-local under shard_map: each device vmaps only its own clients.
+    Left to the SPMD partitioner instead, the vmapped round hands it the
+    client-grouped convolutions vmap makes of a per-client conv (forward
+    and weight gradient), which it splits wrongly — the detector's sharded
+    round then diverges from the one-device round."""
+
+    def train_clients(params, opt, batch, mask, spmd_axis_name=fed.client_axis):
         if fed.participation == "masked":
-            on = jnp.ones((fed.n_clients,), jnp.float32) if mask is None else mask
-            return jax.vmap(gated_local_train, spmd_axis_name=fed.client_axis)(
+            rows = jax.tree.leaves(params)[0].shape[:1]  # the shard's clients under shard_map
+            on = jnp.ones(rows, jnp.float32) if mask is None else mask
+            return jax.vmap(gated_local_train, spmd_axis_name=spmd_axis_name)(
                 on, params, opt, batch
             )
-        return jax.vmap(local_train, spmd_axis_name=fed.client_axis)(params, opt, batch)
+        return jax.vmap(local_train, spmd_axis_name=spmd_axis_name)(params, opt, batch)
 
-    return train_clients
+    if _client_shards(fed, mesh) <= 1:
+        return train_clients
+    rows = P(fed.client_axis)
+    return jax.shard_map(
+        functools.partial(train_clients, spmd_axis_name=None),
+        mesh=mesh, in_specs=(rows, rows, rows, rows), out_specs=(rows, rows, rows),
+        axis_names={fed.client_axis}, check_vma=False,
+    )
 
 
 def _round_metrics(fed: FedConfig, loss, mask):
@@ -547,7 +563,7 @@ def _build_flat_round(cfg: ArchConfig, fed: FedConfig, optimizer: Optimizer, agg
     spec = agg.ctx.spec
     tpl = agg.ctx.template
     local_train, gated = _local_training(cfg, fed, optimizer)
-    train_clients = _train_clients_fn(fed, local_train, gated)
+    train_clients = _train_clients_fn(fed, local_train, gated, mesh)
     constrain = None
     if _client_shards(fed, mesh) > 1:
         if fed.n_clients % _client_shards(fed, mesh):

@@ -229,12 +229,13 @@ class ServeStats:
     status_requests: int = 0
     protocol_errors: int = 0  # malformed INFER payloads (connection dropped)
     crc_errors: int = 0
+    failed: int = 0  # requests answered with an ERROR frame (batch raised)
 
     @property
     def in_flight(self) -> int:
         """Requests accepted but not yet answered; 0 once the service is
         quiescent — the hot-swap bench's zero-dropped-requests check."""
-        return self.requests - self.results
+        return self.requests - self.results - self.failed
 
     @property
     def avg_occupancy(self) -> float:
@@ -249,6 +250,7 @@ class ServeStats:
             "in_flight": self.in_flight,
             "status_requests": self.status_requests,
             "protocol_errors": self.protocol_errors,
+            "failed": self.failed,
         }
 
 
@@ -264,6 +266,11 @@ class InferenceService:
     slot's detections + the snapshot's round version + the freshness tier.
     STATUS frames are answered from the reader (they never touch the jit)
     through the same :func:`model_status` evaluator the monitor uses.
+
+    A batch that raises (a program the compiler refused, say) fails loudly:
+    the first exception is kept in ``error``, that batch's and every later
+    request is answered with an ERROR frame carrying its message (the
+    client raises it), and :meth:`stop` re-raises it.
 
     ``latest_version``: callable returning the newest landed training
     round (e.g. ``lambda: engine.version``) — what rounds-behind is
@@ -289,6 +296,7 @@ class InferenceService:
         self._q: queue.Queue = queue.Queue()
         self._send_locks: dict[int, threading.Lock] = {}
         self._stopping = threading.Event()
+        self.error: Exception | None = None  # first batch failure, re-raised by stop()
         self._listener = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
         self._listener.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
         self._listener.bind((host, port))
@@ -330,6 +338,8 @@ class InferenceService:
             pass
         for t in self._threads:
             t.join(timeout=5.0)
+        if self.error is not None:
+            raise self.error
 
     # -- reader side ---------------------------------------------------------
 
@@ -416,7 +426,21 @@ class InferenceService:
                     items.append(self._q.get(timeout=left))
                 except queue.Empty:
                     break
-            self._run_batch(items)
+            if self.error is None:
+                try:
+                    self._run_batch(items)
+                    continue
+                except Exception as e:  # noqa: BLE001 — surfaced, never swallowed
+                    self.error = e
+            self._fail_batch(items)
+
+    def _fail_batch(self, items: list) -> None:
+        """Answer every request of a batch with the service's error."""
+        msg = f"{type(self.error).__name__}: {self.error}"
+        with self._stats_lock:
+            self.stats.failed += len(items)
+        for sock, rid, _ in items:
+            self._send(sock, wire.pack_error(rid, msg))
 
     def _run_batch(self, items: list) -> None:
         # ONE slot snapshot per batch: the whole batch — and every RESULT in
@@ -450,6 +474,11 @@ class InferenceService:
 
 
 # -- the consumer half -------------------------------------------------------
+
+class ServiceError(RuntimeError):
+    """The service failed the request; the message is the server's own
+    exception (type and text), e.g. the compiler's refusal."""
+
 
 @dataclasses.dataclass
 class ServeResult:
@@ -498,6 +527,9 @@ class InferenceClient:
             if ftype == wire.RESULT:
                 rid, version, tier_code, dets = wire.parse_result(payload)
                 return ServeResult(rid, version, TIER_NAMES[tier_code], dets)
+            if ftype == wire.ERROR:
+                rid, msg = wire.parse_error(payload)
+                raise ServiceError(f"request {rid}: {msg}")
 
     def infer(self, image) -> ServeResult:
         rid = self.send_infer(image)

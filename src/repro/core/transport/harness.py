@@ -106,7 +106,9 @@ def spawn_worker(meta_path: str, host: str, port: int, client_ids: list[int],
     env = {
         **os.environ,
         "PYTHONPATH": f"{src}{os.pathsep}{os.environ.get('PYTHONPATH', '')}".rstrip(os.pathsep),
-        "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu"),
+        # always the CPU, whatever the parent runs on: a chip belongs to one
+        # process, and the parent that launched the federation holds it
+        "JAX_PLATFORMS": "cpu",
     }
     return subprocess.Popen(
         worker_cmd(meta_path, host, port, client_ids, extra),

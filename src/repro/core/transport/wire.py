@@ -41,6 +41,10 @@ not a federated trainer):
     STATUS     empty payload = request; response = a UTF-8 JSON blob, the
                `serving.model_status` evaluation (version, rounds/seconds
                behind, freshness tier, occupancy counters).
+    ERROR      (server -> client) request_id u32 (echo), then the UTF-8
+               message of the exception that failed the request's batch
+               (a program the compiler refused, say) — the consumer raises
+               it instead of waiting out a socket timeout.
 
 The CRC is the corruption firewall (DESIGN.md §16): a flipped byte anywhere
 in the body is *detected* — the parser counts it in ``crc_errors`` and
@@ -62,7 +66,7 @@ from __future__ import annotations
 import struct
 import zlib
 
-PROTOCOL_VERSION = 3  # v3: serving frames (INFER/RESULT/STATUS); v2: CRC32
+PROTOCOL_VERSION = 4  # v4: ERROR frame; v3: serving frames (INFER/RESULT/STATUS); v2: CRC32
 
 HELLO = 1
 DISPATCH = 2
@@ -72,8 +76,9 @@ BYE = 5
 INFER = 6
 RESULT = 7
 STATUS = 8
+ERROR = 9
 
-FRAME_TYPES = (HELLO, DISPATCH, UPDATE, HEARTBEAT, BYE, INFER, RESULT, STATUS)
+FRAME_TYPES = (HELLO, DISPATCH, UPDATE, HEARTBEAT, BYE, INFER, RESULT, STATUS, ERROR)
 
 _LEN = struct.Struct("!I")
 _CRC = struct.Struct("!I")
@@ -83,6 +88,7 @@ _UPDATE = struct.Struct("!IIQf")
 _HEARTBEAT = struct.Struct("!I")
 _INFER = struct.Struct("!IHH")
 _RESULT = struct.Struct("!IQBH")
+_ERROR = struct.Struct("!I")
 _DET = struct.Struct("!ifffff")  # label, score, box (x, y, w, h)
 
 HEADER_BYTES = _LEN.size + _CRC.size  # per-frame framing overhead before the body
@@ -274,3 +280,13 @@ def parse_status(payload: bytes) -> dict | None:
     if not payload:
         return None
     return json.loads(payload.decode("utf-8"))
+
+
+def pack_error(request_id: int, message: str) -> bytes:
+    return encode_frame(ERROR, _ERROR.pack(request_id) + message.encode("utf-8"))
+
+
+def parse_error(payload: bytes) -> tuple[int, str]:
+    """-> (request_id, message)."""
+    (request_id,) = _ERROR.unpack_from(payload, 0)
+    return request_id, payload[_ERROR.size:].decode("utf-8", "replace")

@@ -1,4 +1,4 @@
-"""Pallas TPU kernels (validated via interpret=True on CPU) + jnp oracles."""
+"""Pallas TPU kernels (compiled on TPU, interpreted on CPU: `ops.interpret_mode`) + jnp oracles."""
 from repro.kernels import ops, ref
 
 __all__ = ["ops", "ref"]

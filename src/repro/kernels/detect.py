@@ -10,9 +10,10 @@ the lane axis (4 coordinates), so tiles block only the pair dims.
 
 ``nms`` — fixed-size, score-sorted, mask-based non-maximum suppression
 with jit-stable shapes: the wrapper sorts by score (stable, so score ties
-break by original index) and the kernel runs one grid step per image,
-walking the N sorted boxes with a `fori_loop` that zeroes later boxes
-overlapping a still-kept earlier box. The output is a 0/1 keep mask in the
+break by original index) and the kernel runs one grid step per image: it
+writes the (N, N) IoU matrix of the sorted boxes to VMEM, then walks the
+rows with a `fori_loop` that zeroes later boxes overlapping a still-kept
+earlier box. The output is a 0/1 keep mask in the
 *original* box order, never a dynamic-length index list — the whole eval
 stays one compiled program.
 
@@ -27,6 +28,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import ops
 
 BLOCK_BOXES = 128
 IOU_EPS = 1e-9
@@ -86,7 +90,7 @@ def pairwise_iou(
     boxes_b: jax.Array,
     *,
     giou: bool = False,
-    interpret: bool = True,
+    interpret: bool | None = None,
     block_n: int = BLOCK_BOXES,
     block_m: int = BLOCK_BOXES,
 ) -> jax.Array:
@@ -117,29 +121,29 @@ def pairwise_iou(
         ],
         out_specs=pl.BlockSpec((1, bn, bm), lambda b, i, j: (b, i, j)),
         out_shape=jax.ShapeDtypeStruct((B, N + pad_n, M + pad_m), jnp.float32),
-        interpret=interpret,
+        interpret=ops.interpret_mode(interpret),
     )(boxes_a.astype(jnp.float32), boxes_b.astype(jnp.float32))
     out = out[:, :N, :M]
     return out[0] if squeeze else out
 
 
-def _nms_kernel(boxes_ref, valid_ref, keep_ref, *, iou_thresh):
+def _nms_kernel(boxes_ref, valid_ref, keep_ref, iou_ref, *, iou_thresh):
     boxes = boxes_ref[0].astype(jnp.float32)  # (N, 4) score-sorted desc
     n = boxes.shape[0]
-    x1, y1, x2, y2, area = _corners(boxes)
-    pos = jax.lax.iota(jnp.int32, n)
+    # all pairs at once (the same IEEE ops as pairwise_iou), then the
+    # sequential walk reads row i from VMEM: no vector is ever indexed by
+    # the traced loop counter, which the TPU lowering cannot do
+    iou_ref[...] = _iou_tile(boxes, boxes, giou=False)
+    pos = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
 
-    def body(i, keep):
-        ix = jnp.maximum(jnp.minimum(x2[i], x2) - jnp.maximum(x1[i], x1), 0.0)
-        iy = jnp.maximum(jnp.minimum(y2[i], y2) - jnp.maximum(y1[i], y1), 0.0)
-        inter = _area(ix * iy)
-        iou = inter / jnp.maximum(area[i] + area - inter, IOU_EPS)
+    def body(i, keep):  # keep: (1, N) 0/1
+        kept_i = jnp.max(jnp.where(pos == i, keep, 0.0))
         # a box only suppresses *later* boxes, and only while itself kept —
         # suppressed boxes never cascade (sequential NMS semantics)
-        suppress = (pos > i) & (iou > iou_thresh) & (keep[i] > 0)
+        suppress = (pos > i) & (iou_ref[pl.ds(i, 1), :] > iou_thresh) & (kept_i > 0)
         return jnp.where(suppress, 0.0, keep)
 
-    keep_ref[0] = jax.lax.fori_loop(0, n, body, valid_ref[0].astype(jnp.float32))
+    keep_ref[0] = jax.lax.fori_loop(0, n, body, valid_ref[0])
 
 
 @functools.partial(jax.jit, static_argnames=("iou_thresh", "score_thresh", "max_keep", "interpret"))
@@ -150,7 +154,7 @@ def nms(
     iou_thresh: float = 0.5,
     score_thresh: float = 0.0,
     max_keep: int = 0,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """boxes (B?, N, 4), scores (B?, N) -> keep mask (B?, N) f32, original order.
 
@@ -170,17 +174,20 @@ def nms(
     boxes_s = jnp.take_along_axis(boxes.astype(jnp.float32), order[..., None], axis=1)
     valid_s = (jnp.take_along_axis(scores, order, axis=1) > score_thresh).astype(jnp.float32)
     B, N = valid_s.shape
+    # valid/keep ride as (B, 1, N): every block's last two dims then equal
+    # the array's own, which the TPU lowering requires for N off the lanes
     keep_s = pl.pallas_call(
         functools.partial(_nms_kernel, iou_thresh=iou_thresh),
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, N, 4), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, N), lambda b: (b, 0)),
+            pl.BlockSpec((1, 1, N), lambda b: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, N), lambda b: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, N), jnp.float32),
-        interpret=interpret,
-    )(boxes_s, valid_s)
+        out_specs=pl.BlockSpec((1, 1, N), lambda b: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, 1, N), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
+        interpret=ops.interpret_mode(interpret),
+    )(boxes_s, valid_s[:, None, :])[:, 0, :]
     if max_keep:
         rank = jnp.cumsum(keep_s, axis=-1)  # survivor rank in score order
         keep_s = keep_s * (rank <= max_keep).astype(jnp.float32)

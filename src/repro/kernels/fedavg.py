@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import ops
+
 BLOCK_N = 1024
 
 
@@ -24,7 +26,7 @@ def _kernel(x_ref, wm_ref, den_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_n"))
-def fedavg_masked_mean(stacked: jax.Array, weights: jax.Array, mask: jax.Array, *, interpret: bool = True, block_n: int = BLOCK_N) -> jax.Array:
+def fedavg_masked_mean(stacked: jax.Array, weights: jax.Array, mask: jax.Array, *, interpret: bool | None = None, block_n: int = BLOCK_N) -> jax.Array:
     """stacked (C, N) -> (N,). N padded to block_n internally."""
     C, N = stacked.shape
     pad = (-N) % block_n
@@ -43,6 +45,6 @@ def fedavg_masked_mean(stacked: jax.Array, weights: jax.Array, mask: jax.Array, 
         ],
         out_specs=pl.BlockSpec((block_n,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((npad,), stacked.dtype),
-        interpret=interpret,
+        interpret=ops.interpret_mode(interpret),
     )(stacked, wm, den)
     return out[:N]

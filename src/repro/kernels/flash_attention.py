@@ -16,6 +16,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import ops
+
 BLOCK_Q = 128
 BLOCK_K = 128
 NEG_INF = -1e30
@@ -83,7 +85,7 @@ def flash_attention(
     window: int = 0,
     block_q: int = BLOCK_Q,
     block_k: int = BLOCK_K,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """q (B,H,S,hd); k/v (B,Hkv,S,hd) -> (B,H,S,hd). S % blocks == 0."""
     B, H, S, hd = q.shape
@@ -110,5 +112,5 @@ def flash_attention(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=ops.interpret_mode(interpret),
     )(q, k, v)

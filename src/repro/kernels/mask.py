@@ -3,8 +3,10 @@
 `masked_u32_sum` is the server side of the packed Bonawitz transport: the
 participation-gated uint32 sum of the masked client rows, on the same 2-D
 (N-block x client-block) accumulating grid as `kernels.pack`. All
-arithmetic is mod-2^32 (uint32 lanes wrap), which IS the masking ring — the
-pairwise masks cancel bit-exactly in this sum, not to float tolerance.
+arithmetic is mod-2^32, which IS the masking ring — the pairwise masks
+cancel bit-exactly in this sum, not to float tolerance. The kernel adds the
+rows' bits as int32 (the TPU reduces no unsigned type); two's-complement
+addition wraps identically mod 2^32, so the uint32 result is unchanged.
 Mask construction itself stays in `packing.secure_client_masks` (shared by
 the ref and kernel paths); only the hot gated reduction lives here.
 """
@@ -16,16 +18,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import ops
 from repro.kernels.pack import BLOCK_N, _pad_rows, client_block
 
 
 def _masked_sum_kernel(x_ref, pm_ref, out_ref):
     ci = pl.program_id(1)
-    x = x_ref[...]  # (BC, BN) uint32 masked rows
+    x = x_ref[...]  # (BC, BN) masked rows, uint32 bits viewed as int32
     pm = pm_ref[...].astype(jnp.float32)  # (BC, 1) participation
-    partial = jnp.sum(
-        jnp.where(pm > 0, x, jnp.uint32(0)), axis=0, dtype=jnp.uint32
-    )
+    partial = jnp.sum(jnp.where(pm > 0, x, 0), axis=0, dtype=jnp.int32)
 
     @pl.when(ci == 0)
     def _():
@@ -38,7 +39,7 @@ def _masked_sum_kernel(x_ref, pm_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_n", "block_c"))
 def masked_u32_sum(
-    rows: jax.Array, participation: jax.Array, *, interpret: bool = True,
+    rows: jax.Array, participation: jax.Array, *, interpret: bool | None = None,
     block_n: int = BLOCK_N, block_c: int | None = None,
 ) -> jax.Array:
     """rows (C, N) uint32 + participation (C,) -> (N,) uint32 modular sum
@@ -62,7 +63,7 @@ def masked_u32_sum(
             pl.BlockSpec((bc, 1), lambda j, ci: (ci, 0)),
         ],
         out_specs=pl.BlockSpec((block_n,), lambda j, ci: (j,)),
-        out_shape=jax.ShapeDtypeStruct((N + pad,), jnp.uint32),
-        interpret=interpret,
-    )(rows, pmp)
-    return out[:N]
+        out_shape=jax.ShapeDtypeStruct((N + pad,), jnp.int32),
+        interpret=ops.interpret_mode(interpret),
+    )(jax.lax.bitcast_convert_type(rows, jnp.int32), pmp)
+    return jax.lax.bitcast_convert_type(out[:N], jnp.uint32)

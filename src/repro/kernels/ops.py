@@ -1,9 +1,17 @@
-"""Jit'd public wrappers around the Pallas kernels.
+"""Jit'd public wrappers around the Pallas kernels, and the one switch
+between compiled and interpreted kernels.
 
-`interpret` defaults to True: this container is CPU-only, so kernels execute
-their bodies in interpret mode; on real TPU pass interpret=False. The
-wrappers compose kernels into the shapes the rest of the framework uses
-(pytree-wide aggregation, full SSD with the inter-chunk recurrence, etc.).
+Every kernel takes ``interpret: bool | None = None`` and passes it through
+:func:`interpret_mode` when it is traced: None means "decide from the
+backend" — interpreted on ``cpu`` (tests), compiled on ``tpu``, an error
+anywhere else. No path falls back to the interpreter or to the jnp
+reference on the chip. The wrappers compose kernels into the shapes the
+rest of the framework uses (pytree-wide aggregation, full SSD with the
+inter-chunk recurrence, etc.).
+
+The kernel modules import this one while it imports them; that cycle is
+safe because they read `interpret_mode` only at trace time and
+`repro.kernels` imports `ops` first.
 """
 from __future__ import annotations
 
@@ -22,6 +30,25 @@ from repro.kernels import ssd_scan as _ssd
 
 PyTree = Any
 
+# backend -> interpret mode for kernels called with interpret=None
+_INTERPRET_ON = {"cpu": True, "tpu": False}
+
+
+def interpret_mode(interpret: bool | None = None) -> bool:
+    """Resolve a kernel's ``interpret`` flag. An explicit bool wins (tests
+    pin interpret mode, compile checks force compiled kernels); None reads
+    ``jax.default_backend()``. Called while a kernel is traced, never at
+    import, so importing a kernel initializes no backend."""
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend not in _INTERPRET_ON:
+        raise RuntimeError(
+            f"no Pallas kernel mode for backend {backend!r}: kernels compile "
+            "on tpu and run interpreted on cpu"
+        )
+    return _INTERPRET_ON[backend]
+
 fedavg_masked_mean = _fedavg.fedavg_masked_mean
 pairwise_iou = _detect.pairwise_iou
 nms = _detect.nms
@@ -34,7 +61,7 @@ flash_attention = _flash.flash_attention
 ssd_chunk_scan = _ssd.ssd_chunk_scan
 
 
-def flash_attention_trainable(q, k, v, *, causal: bool = True, window: int = 0, interpret: bool = True):
+def flash_attention_trainable(q, k, v, *, causal: bool = True, window: int = 0, interpret: bool | None = None):
     """Flash-kernel forward with the jnp-reference VJP (training-safe).
 
     The Pallas kernel implements only the forward pass; custom_vjp pairs it
@@ -59,7 +86,7 @@ def flash_attention_trainable(q, k, v, *, causal: bool = True, window: int = 0, 
     return fa(q, k, v)
 
 
-def ssd_full_trainable(xdt, dA, Bm, Cm, *, chunk: int = 128, interpret: bool = True):
+def ssd_full_trainable(xdt, dA, Bm, Cm, *, chunk: int = 128, interpret: bool | None = None):
     """ssd_full forward (Pallas intra-chunk) with the jnp-reference VJP."""
     from repro.models.mamba2 import ssd_chunked
 
@@ -78,7 +105,7 @@ def ssd_full_trainable(xdt, dA, Bm, Cm, *, chunk: int = 128, interpret: bool = T
     return ssd(xdt, dA, Bm, Cm)
 
 
-def fedavg_tree(stacked: PyTree, weights: jax.Array, mask_per_leaf: PyTree, *, interpret: bool = True) -> PyTree:
+def fedavg_tree(stacked: PyTree, weights: jax.Array, mask_per_leaf: PyTree, *, interpret: bool | None = None) -> PyTree:
     """Kernel-backed Eq.5+Eq.6 over a client-stacked pytree.
 
     mask_per_leaf: (C,) upload mask per leaf (from Eq. 6 layer scores).
@@ -94,7 +121,7 @@ def fedavg_tree(stacked: PyTree, weights: jax.Array, mask_per_leaf: PyTree, *, i
     return jax.tree.map(agg, stacked, mask_per_leaf)
 
 
-def quantize_tree(tree: PyTree, *, interpret: bool = True) -> PyTree:
+def quantize_tree(tree: PyTree, *, interpret: bool | None = None) -> PyTree:
     """Per-leaf int8 block quantization -> {"q", "scales"} leaves."""
     return jax.tree.map(
         lambda x: dict(zip(("q", "scales"), _quant.quantize(x.reshape(-1), interpret=interpret))),
@@ -102,7 +129,7 @@ def quantize_tree(tree: PyTree, *, interpret: bool = True) -> PyTree:
     )
 
 
-def dequantize_tree(qtree: PyTree, like: PyTree, *, interpret: bool = True) -> PyTree:
+def dequantize_tree(qtree: PyTree, like: PyTree, *, interpret: bool | None = None) -> PyTree:
     return jax.tree.map(
         lambda qt, x: _quant.dequantize(qt["q"], qt["scales"], dtype=x.dtype, interpret=interpret).reshape(x.shape),
         qtree,
@@ -111,7 +138,7 @@ def dequantize_tree(qtree: PyTree, like: PyTree, *, interpret: bool = True) -> P
     )
 
 
-def ssd_full(xdt: jax.Array, dA: jax.Array, Bm: jax.Array, Cm: jax.Array, *, chunk: int = 128, interpret: bool = True, init_state: jax.Array | None = None):
+def ssd_full(xdt: jax.Array, dA: jax.Array, Bm: jax.Array, Cm: jax.Array, *, chunk: int = 128, interpret: bool | None = None, init_state: jax.Array | None = None):
     """Full SSD = Pallas intra-chunk kernel + lax.scan inter-chunk pass.
 
     Same contract as models.mamba2.ssd_chunked: returns (y (B,S,H,P),

@@ -9,9 +9,10 @@ is exactly why the monolithic launches lost to the per-leaf tree path once
 C x BLOCK_N outgrew VMEM.
 
 `packed_bucket_reduce` additionally tiles the bucket -> weight recovery:
-per N-block the one-hot matmul runs over a ``bucket_tile`` window of the
-(C, B) weight-mask (a block of a sorted-id buffer touches few buckets;
-`packing.bucket_tile_bound` gives the static bound), not all B columns.
+per N-block the one-hot matmul runs over a lane-aligned window of the
+(C, B) weight-mask wide enough for ``bucket_tile`` buckets (a block of a
+sorted-id buffer touches few buckets; `packing.bucket_tile_bound` gives the
+static bound), not all B columns.
 
 `quant8_reduce` fuses the int8 transport into the reduction — encode
 (per-block amax scale, round, clip), decode, and the weighted client sum in
@@ -34,9 +35,17 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import ops
 
 BLOCK_N = 1024
 BLOCK_C = 8
+LANES = 128  # TPU vector lane width: dynamic lane offsets must be multiples
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
 
 
 def client_block(C: int) -> int:
@@ -55,22 +64,25 @@ def _pad_rows(x: jax.Array, block_c: int) -> jax.Array:
     return jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1)) if pad else x
 
 
-def _reduce_kernel(x_ref, wm_ref, pm_ref, bid_ref, b0_ref, num_ref, den_ref, *, bucket_tile):
+def _reduce_kernel(x_ref, wm_ref, pm_ref, bid_ref, b0_ref, num_ref, den_ref, *, window):
     ci = pl.program_id(1)
     x = x_ref[...].astype(jnp.float32)  # (BC, BN)
-    wm = wm_ref[...].astype(jnp.float32)  # (BC, B + TB) zero-padded columns
     pm = pm_ref[...].astype(jnp.float32)  # (BC, 1) participation mask
-    b0 = b0_ref[0]  # first bucket this N-block touches
+    # first weight column of this N-block's window (SMEM, LANES-aligned)
+    b0 = pl.multiple_of(b0_ref[pl.program_id(0)], LANES)
     bn = x.shape[1]
-    # bucket-tiled weight recovery: slice the TB-wide bucket window, then
-    # one-hot matmul on the MXU over TB columns instead of all B. Padding
-    # positions carry bucket id B, which lands in the zero-padded columns.
-    wt = jax.lax.dynamic_slice(wm * pm, (0, b0), (wm.shape[0], bucket_tile))
-    local = bid_ref[...] - b0  # (BN,) in [0, TB) for real elements
+    # bucket-tiled weight recovery: load the window of the zero-padded
+    # weights, then one-hot matmul on the MXU over its columns instead of
+    # all B. Padding positions carry bucket id B, a zero column.
+    wt = wm_ref[:, pl.ds(b0, window)].astype(jnp.float32) * pm
+    local = bid_ref[...] - b0  # (BN,) in [0, window)
     onehot = (
-        jax.lax.broadcasted_iota(jnp.int32, (bucket_tile, bn), 0) == local[None, :]
+        jax.lax.broadcasted_iota(jnp.int32, (window, bn), 0) == local[None, :]
     ).astype(jnp.float32)
-    w = jnp.dot(wt, onehot, preferred_element_type=jnp.float32)  # (BC, BN)
+    # exact: one nonzero product per output; HIGHEST keeps the f32 weights
+    # from being rounded to bf16 by a single MXU pass on the TPU
+    w = jnp.dot(wt, onehot, preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST)  # (BC, BN)
     pnum = jnp.sum(x * w, axis=0)
     pden = jnp.sum(w, axis=0)
 
@@ -92,7 +104,7 @@ def packed_bucket_reduce(
     bucket_ids: jax.Array,
     mask: jax.Array | None = None,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
     block_n: int = BLOCK_N,
     block_c: int | None = None,
     bucket_tile: int | None = None,
@@ -123,21 +135,26 @@ def packed_bucket_reduce(
     bc = min(client_block(C) if block_c is None else block_c, C)
     packed = _pad_rows(packed, bc)
     cpad = packed.shape[0]
-    # zero-pad TB weight columns so the dynamic_slice window never reads
-    # real buckets' weights for padding ids, and zero-weight padding rows
-    wmp = jnp.pad(wmask.astype(jnp.float32), ((0, cpad - C), (0, tb)))
+    # Ids span [0, B] (B = padding), so W zero-padded weight columns hold
+    # them all. A block's window starts at its smallest id rounded down to
+    # the lane width and spans tb buckets past it; it slides back where it
+    # would run off the end (a window of all W columns starts at 0).
+    W = _round_up(B + 1, LANES)
+    window = min(_round_up(tb + LANES - 1, LANES), W)
+    wmp = jnp.pad(wmask.astype(jnp.float32), ((0, cpad - C), (0, W - B)))
     pmp = jnp.pad(mask.astype(jnp.float32).reshape(C, 1), ((0, cpad - C), (0, 0)))
     ids = bucket_ids.astype(jnp.int32)
-    b0 = jnp.min(ids.reshape(npad // block_n, block_n), axis=1)  # (nblocks,)
+    first = jnp.min(ids.reshape(npad // block_n, block_n), axis=1)  # (nblocks,)
+    b0 = jnp.minimum(first // LANES * LANES, W - window)
     num, den = pl.pallas_call(
-        functools.partial(_reduce_kernel, bucket_tile=tb),
+        functools.partial(_reduce_kernel, window=window),
         grid=(npad // block_n, cpad // bc),
         in_specs=[
             pl.BlockSpec((bc, block_n), lambda j, ci: (ci, j)),
-            pl.BlockSpec((bc, B + tb), lambda j, ci: (ci, 0)),
+            pl.BlockSpec((bc, W), lambda j, ci: (ci, 0)),
             pl.BlockSpec((bc, 1), lambda j, ci: (ci, 0)),
             pl.BlockSpec((block_n,), lambda j, ci: (j,)),
-            pl.BlockSpec((1,), lambda j, ci: (j,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=[
             pl.BlockSpec((block_n,), lambda j, ci: (j,)),
@@ -147,7 +164,7 @@ def packed_bucket_reduce(
             jax.ShapeDtypeStruct((npad,), jnp.float32),
             jax.ShapeDtypeStruct((npad,), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=ops.interpret_mode(interpret),
     )(packed, wmp, pmp, ids, b0)
     return num[:N], den[:N]
 
@@ -160,13 +177,13 @@ def _rowquant_kernel(x_ref, q_ref, s_ref, *, block):
     scale = jnp.maximum(amax, 1e-12) / 127.0
     q = jnp.clip(jnp.round(xb / scale[..., None]), -127, 127)
     q_ref[...] = q.reshape(bc, bn).astype(jnp.int8)
-    s_ref[...] = scale
+    s_ref[0] = scale
 
 
 def _rowdequant_kernel(q_ref, s_ref, o_ref, *, block):
     q = q_ref[...].astype(jnp.float32)
     bc, bn = q.shape
-    d = q.reshape(bc, bn // block, block) * s_ref[...][..., None]
+    d = q.reshape(bc, bn // block, block) * s_ref[0][..., None]
     o_ref[...] = d.reshape(bc, bn).astype(o_ref.dtype)
 
 
@@ -178,9 +195,22 @@ def _quant_grid(C, N, block, block_n, block_c):
     return bn, pad, bc
 
 
+# The scale sideband of `quantize_rows`/`dequantize_rows` is (C, nb) to the
+# caller but (N-steps, C, bn/block) to the kernel: each grid step's scales
+# are then a block whose last dim is the array's own, as the TPU requires.
+def _scales_to_steps(s, bn, block):
+    C, nb = s.shape
+    return s.reshape(C, nb * block // bn, bn // block).transpose(1, 0, 2)
+
+
+def _scales_from_steps(s):
+    steps, C, per = s.shape
+    return s.transpose(1, 0, 2).reshape(C, steps * per)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret", "block", "block_n", "block_c"))
 def quantize_rows(
-    x: jax.Array, *, interpret: bool = True, block: int = BLOCK_N,
+    x: jax.Array, *, interpret: bool | None = None, block: int = BLOCK_N,
     block_n: int = 4 * BLOCK_N, block_c: int = BLOCK_C,
 ):
     """x (C, N) -> (q int8 (C, N), scales f32 (C, ceil(N/block))).
@@ -195,7 +225,6 @@ def quantize_rows(
         x = jnp.pad(x, ((0, 0), (0, pad)))
     x = _pad_rows(x, bc)
     cpad = x.shape[0]
-    nb = (N + pad) // block
     nb_real = -(-N // block)  # ceil: the scale sideband's real width
     q, s = pl.pallas_call(
         functools.partial(_rowquant_kernel, block=block),
@@ -203,20 +232,20 @@ def quantize_rows(
         in_specs=[pl.BlockSpec((bc, bn), lambda j, ci: (ci, j))],
         out_specs=[
             pl.BlockSpec((bc, bn), lambda j, ci: (ci, j)),
-            pl.BlockSpec((bc, bn // block), lambda j, ci: (ci, j)),
+            pl.BlockSpec((1, bc, bn // block), lambda j, ci: (j, ci, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((cpad, N + pad), jnp.int8),
-            jax.ShapeDtypeStruct((cpad, nb), jnp.float32),
+            jax.ShapeDtypeStruct(((N + pad) // bn, cpad, bn // block), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=ops.interpret_mode(interpret),
     )(x)
-    return q[:C, :N], s[:C, :nb_real]
+    return q[:C, :N], _scales_from_steps(s)[:C, :nb_real]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block", "dtype", "block_n", "block_c"))
 def dequantize_rows(
-    q: jax.Array, scales: jax.Array, *, dtype=jnp.float32, interpret: bool = True,
+    q: jax.Array, scales: jax.Array, *, dtype=jnp.float32, interpret: bool | None = None,
     block: int = BLOCK_N, block_n: int = 4 * BLOCK_N, block_c: int = BLOCK_C,
 ) -> jax.Array:
     C, N = q.shape
@@ -227,17 +256,17 @@ def dequantize_rows(
     cpad = q.shape[0]
     nb = (N + pad) // block
     s = jnp.pad(scales, ((0, 0), (0, nb - scales.shape[1])))
-    s = _pad_rows(s, bc)
+    s = _scales_to_steps(_pad_rows(s, bc), bn, block)
     out = pl.pallas_call(
         functools.partial(_rowdequant_kernel, block=block),
         grid=((N + pad) // bn, cpad // bc),
         in_specs=[
             pl.BlockSpec((bc, bn), lambda j, ci: (ci, j)),
-            pl.BlockSpec((bc, bn // block), lambda j, ci: (ci, j)),
+            pl.BlockSpec((1, bc, bn // block), lambda j, ci: (j, ci, 0)),
         ],
         out_specs=pl.BlockSpec((bc, bn), lambda j, ci: (ci, j)),
         out_shape=jax.ShapeDtypeStruct((cpad, N + pad), dtype),
-        interpret=interpret,
+        interpret=ops.interpret_mode(interpret),
     )(q, s)
     return out[:C, :N]
 
@@ -265,7 +294,7 @@ def _quant_reduce_kernel(x_ref, w_ref, num_ref, *, block):
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block", "block_n", "block_c"))
 def quant8_reduce(
-    delta: jax.Array, weights: jax.Array, *, interpret: bool = True,
+    delta: jax.Array, weights: jax.Array, *, interpret: bool | None = None,
     block: int = BLOCK_N, block_n: int = 4 * BLOCK_N, block_c: int = BLOCK_C,
 ) -> jax.Array:
     """Fused int8 transport: delta (C, N) + weights (C,) -> (N,) f32
@@ -291,7 +320,7 @@ def quant8_reduce(
         ],
         out_specs=pl.BlockSpec((bn,), lambda j, ci: (j,)),
         out_shape=jax.ShapeDtypeStruct((N + pad,), jnp.float32),
-        interpret=interpret,
+        interpret=ops.interpret_mode(interpret),
     )(delta, wp)
     return num[:N]
 
@@ -299,21 +328,21 @@ def quant8_reduce(
 def _grouped_kernel(x_ref, w_ref, out_ref):
     ci = pl.program_id(2)
     x = x_ref[0].astype(jnp.float32)  # (BC, BN) member window of one group
-    w = w_ref[...].astype(jnp.float32)  # (1, BC) pre-normalized weights
-    partial = jnp.sum(x * w.reshape(-1, 1), axis=0)
+    w = w_ref[0].astype(jnp.float32)  # (BC, 1) pre-normalized weights
+    partial = jnp.sum(x * w, axis=0, keepdims=True)  # (1, BN)
 
     @pl.when(ci == 0)
     def _():
-        out_ref[...] = partial[None, :]
+        out_ref[0] = partial
 
     @pl.when(ci > 0)
     def _():
-        out_ref[...] += partial[None, :]
+        out_ref[0] += partial
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_n", "block_c"))
 def grouped_reduce(
-    packed: jax.Array, wn: jax.Array, *, interpret: bool = True,
+    packed: jax.Array, wn: jax.Array, *, interpret: bool | None = None,
     block_n: int = BLOCK_N, block_c: int | None = None,
 ) -> jax.Array:
     """Hierarchical inner reduce: packed (C, N) + wn (C/G, G) pre-normalized
@@ -339,15 +368,17 @@ def grouped_reduce(
         xg = jnp.pad(xg, ((0, 0), (0, gpad), (0, 0)))
         wn = jnp.pad(wn, ((0, 0), (0, gpad)))
     npad, Gp = N + pad, G + gpad
+    # weights as (groups, Gp, 1) and rows as (groups, 1, N): every block's
+    # last two dims are then aligned or the array's own, as the TPU requires
     out = pl.pallas_call(
         _grouped_kernel,
         grid=(npad // block_n, ngroups, Gp // bc),
         in_specs=[
             pl.BlockSpec((1, bc, block_n), lambda j, g, ci: (g, ci, j)),
-            pl.BlockSpec((1, bc), lambda j, g, ci: (g, ci)),
+            pl.BlockSpec((1, bc, 1), lambda j, g, ci: (g, ci, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_n), lambda j, g, ci: (g, j)),
-        out_shape=jax.ShapeDtypeStruct((ngroups, npad), jnp.float32),
-        interpret=interpret,
-    )(xg, wn)
-    return out[:, :N]
+        out_specs=pl.BlockSpec((1, 1, block_n), lambda j, g, ci: (g, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((ngroups, 1, npad), jnp.float32),
+        interpret=ops.interpret_mode(interpret),
+    )(xg, wn[..., None])
+    return out[:, 0, :N]

@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import ops
+
 BLOCK = 1024
 
 
@@ -28,7 +30,7 @@ def _dequant_kernel(q_ref, s_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block"))
-def quantize(x: jax.Array, *, interpret: bool = True, block: int = BLOCK):
+def quantize(x: jax.Array, *, interpret: bool | None = None, block: int = BLOCK):
     """x (N,) -> (q int8 (N,), scales f32 (ceil(N/block),)). Pads with 0."""
     N = x.shape[0]
     pad = (-N) % block
@@ -47,13 +49,13 @@ def quantize(x: jax.Array, *, interpret: bool = True, block: int = BLOCK):
             jax.ShapeDtypeStruct((N + pad,), jnp.int8),
             jax.ShapeDtypeStruct((nb,), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=ops.interpret_mode(interpret),
     )(x)
     return q[:N], s
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block", "dtype"))
-def dequantize(q: jax.Array, scales: jax.Array, *, dtype=jnp.float32, interpret: bool = True, block: int = BLOCK) -> jax.Array:
+def dequantize(q: jax.Array, scales: jax.Array, *, dtype=jnp.float32, interpret: bool | None = None, block: int = BLOCK) -> jax.Array:
     N = q.shape[0]
     pad = (-N) % block
     if pad:
@@ -67,6 +69,6 @@ def dequantize(q: jax.Array, scales: jax.Array, *, dtype=jnp.float32, interpret:
         ],
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((N + pad,), dtype),
-        interpret=interpret,
+        interpret=ops.interpret_mode(interpret),
     )(q, scales)
     return out[:N]

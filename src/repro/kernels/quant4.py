@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import ops
 from repro.kernels.pack import BLOCK_C, BLOCK_N, _pad_rows, _quant_grid
 
 _C1 = 0x85EBCA6B
@@ -61,7 +62,8 @@ def _quant4_reduce_kernel(x_ref, w_ref, key_ref, num_ref, *, block, mode):
             + cg.astype(jnp.uint32) * jnp.uint32(_IDX_C)
             + ng.astype(jnp.uint32) * jnp.uint32(_IDX_N)
         )
-        u = (bits >> 8).astype(jnp.float32) * jnp.float32(2.0**-24)
+        # via int32: the TPU converts no uint32 to f32 (bits >> 8 < 2^24, exact)
+        u = jax.lax.bitcast_convert_type(bits >> 8, jnp.int32).astype(jnp.float32) * jnp.float32(2.0**-24)
         # clip AFTER the floor: 7 + u can round to 8.0 in f32
         q = jnp.clip(jnp.floor(v + u.reshape(bc, bn // block, block)), -7, 7)
     d = (q * scale[..., None]).reshape(bc, bn)
@@ -79,7 +81,7 @@ def _quant4_reduce_kernel(x_ref, w_ref, key_ref, num_ref, *, block, mode):
 @functools.partial(jax.jit, static_argnames=("interpret", "block", "mode", "block_n", "block_c"))
 def quant4_reduce(
     delta: jax.Array, weights: jax.Array, key: jax.Array | int = 0, *,
-    mode: str = "nearest", interpret: bool = True,
+    mode: str = "nearest", interpret: bool | None = None,
     block: int = BLOCK_N, block_n: int = 4 * BLOCK_N, block_c: int = BLOCK_C,
 ) -> jax.Array:
     """Fused 4-bit transport: delta (C, N) + weights (C,) [+ uint32 round
@@ -108,6 +110,6 @@ def quant4_reduce(
         ],
         out_specs=pl.BlockSpec((bn,), lambda j, ci: (j,)),
         out_shape=jax.ShapeDtypeStruct((N + pad,), jnp.float32),
-        interpret=interpret,
+        interpret=ops.interpret_mode(interpret),
     )(delta, wp, kv)
     return num[:N]

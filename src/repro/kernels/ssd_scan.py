@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import ops
+
 
 def _kernel(xdt_ref, dA_ref, b_ref, c_ref, y_ref, st_ref, dec_ref, cum_ref):
     xdt = xdt_ref[0, :, 0, :].astype(jnp.float32)  # (Q, P)
@@ -38,7 +40,7 @@ def _kernel(xdt_ref, dA_ref, b_ref, c_ref, y_ref, st_ref, dec_ref, cum_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_chunk_scan(xdt: jax.Array, dA: jax.Array, Bm: jax.Array, Cm: jax.Array, *, chunk: int = 128, interpret: bool = True):
+def ssd_chunk_scan(xdt: jax.Array, dA: jax.Array, Bm: jax.Array, Cm: jax.Array, *, chunk: int = 128, interpret: bool | None = None):
     """Intra-chunk pass. xdt (B,S,H,P); dA (B,S,H); Bm/Cm (B,S,N).
 
     Returns (y_diag (B,S,H,P) f32, states (B,nc,H,P,N) f32,
@@ -69,6 +71,6 @@ def ssd_chunk_scan(xdt: jax.Array, dA: jax.Array, Bm: jax.Array, Cm: jax.Array, 
             jax.ShapeDtypeStruct((B, nc, H), jnp.float32),
             jax.ShapeDtypeStruct((B, S, H), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=ops.interpret_mode(interpret),
     )(xdt, dA, Bm, Cm)
     return out

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 
 from repro.checkpoint import ObjectStore
 from repro.configs import get_arch
+from repro.launch.cache import enable_compile_cache
 from repro.models import params as P
 from repro.models import serving as S
 from repro.models import transformer as T
@@ -190,6 +191,7 @@ def main() -> None:
     ap.add_argument("--full-size", action="store_true",
                     help="use the full config (must match how the stored model was trained)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if not args.full_size:

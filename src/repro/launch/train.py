@@ -47,6 +47,7 @@ from repro.core.server import FLServer
 from repro.data import partition
 from repro.data.pipeline import detection_suite, fed_batches
 from repro.launch import specs
+from repro.launch.cache import enable_compile_cache
 from repro.optim import adamw, sgd
 
 
@@ -256,6 +257,7 @@ def main() -> None:
     ap.add_argument("--store", default="", help="COS object-store directory")
     ap.add_argument("--print-plan", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.replay_schedule:
         _replay_schedule(args.replay_schedule)

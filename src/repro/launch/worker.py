@@ -234,6 +234,9 @@ class _CrashBudget:
 
 def main(argv=None) -> int:
     args = _parse_args(argv)
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     meta = json.loads(open(args.meta).read())
     clients = [int(c) for c in args.client_ids.split(",") if c != ""]
     if not clients:

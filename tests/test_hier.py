@@ -251,7 +251,52 @@ print("SHARDED_OK")
 """
 
 
-def test_sharded_hier_matches_unsharded():
+# The detector's convolutions under a sharded client axis: vmap turns each
+# client's conv into one feature-grouped convolution (and its weight
+# gradient into a batch-grouped one), which XLA's SPMD partitioner splits
+# wrongly; local training therefore runs under shard_map when the client
+# axis is sharded (rounds._train_clients_fn).
+_SHARDED_DETECTOR_SCRIPT = r"""
+import numpy as np
+import jax, jax.numpy as jnp
+from repro.configs import get_arch
+from repro.core import rounds as R
+from repro.core.rounds import FedConfig
+from repro.data.pipeline import detection_suite
+from repro.optim import sgd
+
+CFG = get_arch("fedyolov3").reduced()
+C = 4
+fed = FedConfig(n_clients=C, local_steps=1, aggregation="hier", group_size=2,
+                hier_base="dense", topn=2, client_axis="data", data_axis=None)
+gen, _, _ = detection_suite(CFG, fed, batch=1, img_size=32, scenario="iid", pool_scenes=16)
+batch = jax.tree.map(jnp.asarray, next(gen))
+
+def run(n_shards):
+    opt = sgd(lr=1e-3)
+    mesh = jax.make_mesh((n_shards, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2,
+                         devices=jax.devices()[:n_shards])
+    with jax.set_mesh(mesh):
+        state = R.make_state(CFG, fed, opt, jax.random.key(0))
+        fr = jax.jit(R.build_fed_round(CFG, fed, opt, mesh))
+        w = jnp.asarray([0.4, 0.1, 0.3, 0.2], jnp.float32)
+        for _ in range(2):
+            state, m = fr(state, batch, w)
+    return np.asarray(jax.device_get(state["params"]), np.float64), float(m["loss"])
+
+assert jax.device_count() == 2, jax.device_count()
+p1, l1 = run(1)
+p2, l2 = run(2)
+scale = max(np.max(np.abs(p1)), 1e-9)
+print("MAXDIFF", np.max(np.abs(p1 - p2)) / scale, "LOSSDIFF", abs(l1 - l2) / abs(l1))
+assert np.max(np.abs(p1 - p2)) / scale < 1e-6, np.max(np.abs(p1 - p2)) / scale
+assert abs(l1 - l2) < 1e-6 * abs(l1)
+print("SHARDED_OK")
+"""
+
+
+def _run_on_two_cpu_devices(script: str) -> None:
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=2").strip()
@@ -259,8 +304,16 @@ def test_sharded_hier_matches_unsharded():
         [os.path.join(os.path.dirname(__file__), "..", "src"), env.get("PYTHONPATH", "")]
     )
     out = subprocess.run(
-        [sys.executable, "-c", _SHARDED_SCRIPT], env=env,
+        [sys.executable, "-c", script], env=env,
         capture_output=True, text=True, timeout=420,
     )
     assert out.returncode == 0, out.stdout + out.stderr
     assert "SHARDED_OK" in out.stdout, out.stdout
+
+
+def test_sharded_hier_matches_unsharded():
+    _run_on_two_cpu_devices(_SHARDED_SCRIPT)
+
+
+def test_sharded_hier_detector_matches_unsharded():
+    _run_on_two_cpu_devices(_SHARDED_DETECTOR_SCRIPT)

@@ -1,7 +1,13 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs jnp oracles."""
+"""Per-kernel shape/dtype sweeps: Pallas (interpreted on CPU) vs jnp
+oracles, and the backend switch that picks the kernel mode."""
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops, ref
@@ -12,6 +18,35 @@ RNG = np.random.default_rng(42)
 
 def _arr(shape, dtype=jnp.float32, scale=1.0):
     return jnp.asarray(RNG.normal(size=shape) * scale, dtype)
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False)])
+def test_interpret_mode_follows_the_backend(monkeypatch, backend, interpret):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert ops.interpret_mode() is interpret
+    assert ops.interpret_mode(not interpret) is (not interpret)  # explicit wins
+
+
+def test_interpret_mode_refuses_other_backends(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops.interpret_mode()
+
+
+def test_importing_the_kernels_initializes_no_backend():
+    """The switch reads the backend when a kernel is traced, never at
+    import: a process must still be free to pick its platform after
+    importing the package."""
+    code = (
+        "import repro.kernels, repro.core, repro.core.serving, repro.launch.cache\n"
+        "from jax._src import xla_bridge\n"
+        "assert not xla_bridge.backends_are_initialized()\n"
+        "print('NO_BACKEND')\n"
+    )
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = {**os.environ, "PYTHONPATH": os.pathsep.join([src, os.environ.get("PYTHONPATH", "")])}
+    out = subprocess.run([sys.executable, "-c", code], env=env, capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0 and "NO_BACKEND" in out.stdout, out.stdout + out.stderr
 
 
 @pytest.mark.parametrize("C,N", [(2, 128), (4, 3000), (8, 1024), (3, 17)])

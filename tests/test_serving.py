@@ -330,6 +330,35 @@ def test_wrong_size_image_is_a_protocol_error():
         svc.stop()
 
 
+def test_error_frame_roundtrip():
+    frames = wire.FrameParser().feed(wire.pack_error(9, "RuntimeError: refusé"))
+    assert frames[0][0] == wire.ERROR
+    assert wire.parse_error(frames[0][1]) == (9, "RuntimeError: refusé")
+
+
+def test_failing_program_surfaces_its_error():
+    """A batch whose program raises (a compile refusal, say) fails its
+    requests with the server's own message: the client raises it at once,
+    not after a socket timeout, later requests fail the same way, and
+    stop() re-raises the exception."""
+    _, _, _, svc = serve_ctx()
+
+    def refused(params, images):
+        raise RuntimeError("Mosaic failed to compile TPU kernel: injected")
+
+    svc._program = refused
+    try:
+        with serving.InferenceClient(svc.host, svc.port, timeout=10.0) as client:
+            with pytest.raises(serving.ServiceError, match="Mosaic failed to compile"):
+                client.infer(scenes(1)[0])
+            with pytest.raises(serving.ServiceError, match="injected"):
+                client.infer(scenes(1, seed=1)[0])
+        assert svc.stats.failed == 2 and svc.stats.in_flight == 0
+    finally:
+        with pytest.raises(RuntimeError, match="injected"):
+            svc.stop()
+
+
 def test_hot_swap_under_load_drops_nothing():
     cfg, fed, slot, svc = serve_ctx()
     try:
