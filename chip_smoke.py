@@ -48,6 +48,8 @@ import numpy as np  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
+from repro.core import telemetry  # noqa: E402  (its listener counts every compile)
+
 IMG = 416  # YOLOv3's published input size: 52/26/13 grids
 CLIENTS = 4
 BATCH = 4
@@ -65,30 +67,13 @@ def log(phase: str, msg: str) -> None:
     print(f"[{phase}] {msg}", flush=True)
 
 
-class CompileStats:
-    """Backend compile seconds and persistent-cache hits, from JAX's own
-    monitoring events (a cache hit still records its retrieval time)."""
-
-    def __init__(self):
-        self.compile_s = 0.0
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event: str, duration: float, **_) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += duration
-
-    def _event(self, event: str, **_) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def line(self) -> str:
-        return (f"backend compile {self.compile_s:.3f} s, persistent cache "
-                f"hits {self.hits} misses {self.misses}")
+def compile_line() -> str:
+    """Backend compile seconds and persistent-cache hits so far, from
+    `telemetry`'s compile counters (a cache hit still records its retrieval
+    time)."""
+    c = telemetry.counters()
+    return (f"backend compile {c.get('jax.compile_ns', 0) / 1e9:.3f} s, persistent cache "
+            f"hits {c.get('jax.cache_hits', 0)} misses {c.get('jax.cache_misses', 0)}")
 
 
 def check_device(n_chips: int):
@@ -298,7 +283,6 @@ def main(argv=None) -> int:
     from repro.launch.cache import enable_compile_cache
 
     log("cache", f"persistent compilation cache at {enable_compile_cache()}")
-    stats = CompileStats()
     cfg = get_arch("fedyolov3")  # full width, unreduced
     t0 = time.perf_counter()
     if args.chips == 4:
@@ -309,7 +293,7 @@ def main(argv=None) -> int:
         phase_eval(server, eval_batch)
         phase_serve(cfg, server.global_params(), len(server.history), IMG, fed=server.fed)
         log("serve", f"peak device bytes {dev.memory_stats()['peak_bytes_in_use']}")
-    log("cache", f"{stats.line()}; wall {time.perf_counter() - t0:.3f} s")
+    log("cache", f"{compile_line()}; wall {time.perf_counter() - t0:.3f} s")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind, "count": len(jax.devices())}}))
     return 0

@@ -421,8 +421,10 @@ def _local_training(cfg: ArchConfig, fed: FedConfig, optimizer: Optimizer):
     def local_train(params, opt, client_batch):
         def step(carry, micro):
             p, o = carry
-            loss, grads = grads_of(p, micro)
-            p, o = optimizer.update(p, grads, o)
+            with jax.named_scope("forward_backward"):
+                loss, grads = grads_of(p, micro)
+            with jax.named_scope("optimizer"):
+                p, o = optimizer.update(p, grads, o)
             return (p, o), loss
 
         (params, opt), losses = jax.lax.scan(step, (params, opt), client_batch)
@@ -559,7 +561,12 @@ def _build_flat_round(cfg: ArchConfig, fed: FedConfig, optimizer: Optimizer, agg
     shard-local, the single cross-shard merge lives inside the aggregator,
     and `jit_fed_round` still emits ONE donated program (DESIGN.md §13).
     A 1-shard client axis adds no constraint, keeping the single-device
-    program bit-identical to the meshless build."""
+    program bit-identical to the meshless build.
+
+    Named scopes put each op of the round under ``forward_backward``,
+    ``optimizer`` (both from `_local_training`), ``write_slots`` or
+    ``aggregate`` in its ``op_name``, so a device trace splits the round by
+    them; they change metadata only, not the numbers."""
     spec = agg.ctx.spec
     tpl = agg.ctx.template
     local_train, gated = _local_training(cfg, fed, optimizer)
@@ -595,7 +602,8 @@ def _build_flat_round(cfg: ArchConfig, fed: FedConfig, optimizer: Optimizer, agg
             p_k, o_k, loss = jax.vmap(local_train)(
                 packing.unpack_views(spec, packed, tpl), state["opt"], batch
             )
-            packed_new = packing.write_slots(spec, packed, p_k)
+            with jax.named_scope("write_slots"):
+                packed_new = packing.write_slots(spec, packed, p_k)
             new_o = o_k
         elif fed.participation == "compact":
             # K rows of the packed buffer gather into the compact axis; the
@@ -607,14 +615,17 @@ def _build_flat_round(cfg: ArchConfig, fed: FedConfig, optimizer: Optimizer, agg
             )
             put = lambda full, upd: jax.tree.map(lambda x, u: x.at[idx].set(u), full, upd)
             loss = jnp.zeros((fed.n_clients,), jnp.float32).at[idx].set(loss_k)
-            packed_new = packed.at[idx].set(packing.write_slots(spec, sub, p_k))
+            with jax.named_scope("write_slots"):
+                packed_new = packed.at[idx].set(packing.write_slots(spec, sub, p_k))
             new_o = put(state["opt"], o_k)
         else:
             new_p, new_o, loss = train_clients(
                 packing.unpack_views(spec, packed, tpl), state["opt"], batch, mask
             )
-            packed_new = packing.write_slots(spec, packed, new_p)
-        packed_out, agg_state = agg.aggregate(packed_new, weights, state["agg"], mask)
+            with jax.named_scope("write_slots"):
+                packed_new = packing.write_slots(spec, packed, new_p)
+        with jax.named_scope("aggregate"):
+            packed_out, agg_state = agg.aggregate(packed_new, weights, state["agg"], mask)
         if constrain is not None:
             packed_out = constrain(packed_out)
         out = {

@@ -25,7 +25,6 @@ its async completions.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Callable, Iterator
 
 import numpy as np
@@ -35,7 +34,7 @@ import jax.numpy as jnp
 
 from repro.checkpoint import ObjectStore
 from repro.configs.base import ArchConfig
-from repro.core import aggregators, async_engine, explorer, rounds
+from repro.core import aggregators, async_engine, explorer, rounds, telemetry
 from repro.core.async_engine import (
     AsyncRoundRecord,
     BufferedAsyncEngine,
@@ -55,7 +54,7 @@ class RoundRecord:
     round_idx: int
     loss: float
     weights: list[float]
-    seconds: float
+    seconds: float  # the fl.round span: scheduling to checkpoint, monotonic
     participants: list[int] = dataclasses.field(default_factory=list)
     loads: list[float] = dataclasses.field(default_factory=list)
 
@@ -182,43 +181,49 @@ class FLServer:
                 "FedConfig(mode='async') servers run buffered flushes — call "
                 "run_async(batch) (or fit(), which dispatches on the mode)"
             )
-        t0 = time.time()
-        if self._shared_clock:
-            # shared-clock semantics: this round's report is the load
-            # process state *now*; the round then consumes wait-for-slowest
-            # simulated time and the process evolves over that same span
-            # (stepping by 1.0 here would re-conflate process time with
-            # round count — the cadence bug the §12 Explorer fix removed)
-            loads = self.load_model.loads.copy()
-        else:
-            loads = self.load_model.step()  # legacy: one tick per round
-        sel = self.scheduler.participation(loads, k_static=self._k_static)
-        part = rounds.participation_input(self.fed, sel["mask"], sel["weights"], sel.get("idx"))
-        if self._shared_clock:
-            # the round takes as long as its slowest selected client
-            dur = sync_round_seconds(
-                self.timing, loads, self._upload_s, self.fed.local_steps,
-                mask=sel["mask"],
-            )
-            self.clock.advance(dur)
-            self.load_model.step(dur)
-        self.state, metrics = self._fed_round(self.state, batch, part)
-        loss = float(metrics["loss"])
-        participants = [int(c) for c in np.nonzero(sel["mask"])[0]]
-        client_loss = np.asarray(metrics["client_loss"], np.float32)
-        for c in participants:
-            self.scheduler.report_quality(c, float(client_loss[c]))
-        rec = RoundRecord(
-            len(self.history),
-            loss,
-            [float(w) for w in sel["weights"]],
-            time.time() - t0,
-            participants=participants,
-            loads=[float(x) for x in loads],
-        )
-        self.history.append(rec)
-        if self.store and self.checkpoint_every and rec.round_idx % self.checkpoint_every == 0:
-            self.store.put_model(self.task_id, rec.round_idx, self.global_params(), {"loss": loss})
+        with telemetry.span("fl.round", id=len(self.history)) as span:
+            with telemetry.span("fl.schedule", id=span.id):
+                if self._shared_clock:
+                    # shared-clock semantics: this round's report is the load
+                    # process state *now*; the round then consumes wait-for-slowest
+                    # simulated time and the process evolves over that same span
+                    # (stepping by 1.0 here would re-conflate process time with
+                    # round count — the cadence bug the §12 Explorer fix removed)
+                    loads = self.load_model.loads.copy()
+                else:
+                    loads = self.load_model.step()  # legacy: one tick per round
+                sel = self.scheduler.participation(loads, k_static=self._k_static)
+                part = rounds.participation_input(self.fed, sel["mask"], sel["weights"], sel.get("idx"))
+                if self._shared_clock:
+                    # the round takes as long as its slowest selected client
+                    dur = sync_round_seconds(
+                        self.timing, loads, self._upload_s, self.fed.local_steps,
+                        mask=sel["mask"],
+                    )
+                    self.clock.advance(dur)
+                    self.load_model.step(dur)
+            with telemetry.span("fl.dispatch", id=span.id):
+                self.state, metrics = self._fed_round(self.state, batch, part)
+            with telemetry.span("fl.device_wait", id=span.id):
+                loss = float(metrics["loss"])
+            with telemetry.span("fl.report", id=span.id):
+                participants = [int(c) for c in np.nonzero(sel["mask"])[0]]
+                client_loss = np.asarray(metrics["client_loss"], np.float32)
+                for c in participants:
+                    self.scheduler.report_quality(c, float(client_loss[c]))
+                rec = RoundRecord(
+                    span.id,
+                    loss,
+                    [float(w) for w in sel["weights"]],
+                    0.0,  # the fl.round span's length, set when it closes
+                    participants=participants,
+                    loads=[float(x) for x in loads],
+                )
+                self.history.append(rec)
+            if self.store and self.checkpoint_every and rec.round_idx % self.checkpoint_every == 0:
+                with telemetry.span("fl.checkpoint", id=span.id):
+                    self.store.put_model(self.task_id, rec.round_idx, self.global_params(), {"loss": loss})
+        rec.seconds = (span.end_ns - span.start_ns) / 1e9
         return rec
 
     def run_async(self, batch: PyTree) -> AsyncRoundRecord:

@@ -38,11 +38,12 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 import queue
 import socket
 import threading
 import time
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple
 
 import numpy as np
 
@@ -51,6 +52,7 @@ import jax.numpy as jnp
 
 from repro.core import detection
 from repro.core import rounds as R
+from repro.core import telemetry
 from repro.core.transport import wire
 
 PyTree = Any
@@ -218,6 +220,20 @@ def decode_result(pred: dict, i: int) -> list[tuple[int, float, tuple]]:
 
 # -- the service -------------------------------------------------------------
 
+class _Pending(NamedTuple):
+    """An accepted INFER waiting for the batcher: the reply's socket and
+    wire id, the image, the service's request id and ``serve.request`` span
+    number, and when its first byte arrived and when it was enqueued."""
+
+    sock: socket.socket
+    rid: int
+    img: np.ndarray
+    sid: int
+    seq: int
+    first_ns: int
+    enq_ns: int
+
+
 @dataclasses.dataclass
 class ServeStats:
     """Operational counters (rendered by `monitor.render_serving`)."""
@@ -257,8 +273,8 @@ class ServeStats:
 class InferenceService:
     """Socket-served batched detection over the wire framing.
 
-    Reader threads parse INFER frames and enqueue ``(conn, request_id,
-    image)``; ONE batcher thread (the only jit caller) collects up to
+    Reader threads parse INFER frames and enqueue each with the service's
+    own request id; ONE batcher thread (the only jit caller) collects up to
     ``fed.serve_batch`` requests per launch — the first request opens the
     batch, then the batcher lingers ``fed.serve_max_wait_s`` for the rest
     of the slots — zero-pads to the fixed batch, snapshots the `ModelSlot`
@@ -266,6 +282,8 @@ class InferenceService:
     slot's detections + the snapshot's round version + the freshness tier.
     STATUS frames are answered from the reader (they never touch the jit)
     through the same :func:`model_status` evaluator the monitor uses.
+    Every request and batch leaves its ``serve.*`` spans in `telemetry`'s
+    ring, under the request id and a batch id.
 
     A batch that raises (a program the compiler refused, say) fails loudly:
     the first exception is kept in ``error``, that batch's and every later
@@ -294,6 +312,8 @@ class InferenceService:
         self.stats = ServeStats()
         self._stats_lock = threading.Lock()
         self._q: queue.Queue = queue.Queue()
+        self._request_ids = itertools.count()  # the service's own request ids (the ring's)
+        self._batch_ids = itertools.count()
         self._send_locks: dict[int, threading.Lock] = {}
         self._stopping = threading.Event()
         self.error: Exception | None = None  # first batch failure, re-raised by stop()
@@ -367,6 +387,7 @@ class InferenceService:
 
     def _reader(self, sock: socket.socket) -> None:
         parser = wire.FrameParser()
+        frame_ns = 0  # when the first byte of the frame in progress arrived
         while not self._stopping.is_set():
             try:
                 data = sock.recv(1 << 16)
@@ -374,6 +395,9 @@ class InferenceService:
                 break
             if not data:
                 break
+            got_ns = telemetry.now_ns()
+            if not parser.pending:
+                frame_ns = got_ns
             try:
                 frames = parser.feed(data)
             except ValueError:
@@ -382,6 +406,7 @@ class InferenceService:
                 with self._stats_lock:
                     self.stats.crc_errors += parser.crc_errors
                 break  # poisoned stream (same discipline as the WireServer)
+            parse_ns = got_ns  # serve.parse of the first frame covers the feed's CRC and copies
             for ftype, payload in frames:
                 if ftype == wire.INFER:
                     try:
@@ -400,13 +425,19 @@ class InferenceService:
                         return
                     with self._stats_lock:
                         self.stats.requests += 1
-                    self._q.put((sock, rid, img))
+                    sid, req_seq, recv_seq = next(self._request_ids), telemetry.reserve(), telemetry.reserve()
+                    enq_ns = telemetry.now_ns()
+                    telemetry.add("serve.parse", parse_ns, enq_ns, id=sid, parent=recv_seq)
+                    self._q.put(_Pending(sock, rid, img, sid, req_seq, frame_ns, enq_ns))
+                    parse_ns = telemetry.now_ns()
+                    telemetry.add("serve.recv", frame_ns, parse_ns, id=sid, parent=req_seq, seq=recv_seq)
                 elif ftype == wire.STATUS:
                     with self._stats_lock:
                         self.stats.status_requests += 1
                     self._send(sock, wire.pack_status(self.status()))
                 # anything else on a serving socket is ignored (the federation
                 # frame types belong to the WireServer's port)
+                frame_ns = got_ns  # a later frame's first byte came in this chunk at the earliest
 
     # -- batcher (the only jit caller) ---------------------------------------
 
@@ -416,49 +447,66 @@ class InferenceService:
                 first = self._q.get(timeout=0.05)
             except queue.Empty:
                 continue
-            items = [first]
-            deadline = time.monotonic() + self.max_wait_s
-            while len(items) < self.batch:
-                left = deadline - time.monotonic()
-                if left <= 0:
-                    break
-                try:
-                    items.append(self._q.get(timeout=left))
-                except queue.Empty:
-                    break
-            if self.error is None:
-                try:
-                    self._run_batch(items)
-                    continue
-                except Exception as e:  # noqa: BLE001 — surfaced, never swallowed
-                    self.error = e
-            self._fail_batch(items)
+            with telemetry.span("serve.batch", id=next(self._batch_ids)) as batch:
+                with telemetry.span("serve.linger", id=batch.id):
+                    items = [first]
+                    deadline = time.monotonic() + self.max_wait_s
+                    while len(items) < self.batch:
+                        left = deadline - time.monotonic()
+                        if left <= 0:
+                            break
+                        try:
+                            items.append(self._q.get(timeout=left))
+                        except queue.Empty:
+                            break
+                batch.ids = tuple(it.sid for it in items)
+                closed_ns = telemetry.now_ns()
+                for it in items:
+                    telemetry.add("serve.queue", it.enq_ns, closed_ns, id=it.sid, parent=it.seq)
+                if self.error is None:
+                    try:
+                        self._run_batch(items, batch.id)
+                        continue
+                    except Exception as e:  # noqa: BLE001 — surfaced, never swallowed
+                        self.error = e
+                self._fail_batch(items)
 
     def _fail_batch(self, items: list) -> None:
         """Answer every request of a batch with the service's error."""
         msg = f"{type(self.error).__name__}: {self.error}"
         with self._stats_lock:
             self.stats.failed += len(items)
-        for sock, rid, _ in items:
-            self._send(sock, wire.pack_error(rid, msg))
+        for it in items:
+            self._send(it.sock, wire.pack_error(it.rid, msg))
 
-    def _run_batch(self, items: list) -> None:
+    def _run_batch(self, items: list, bid: int) -> None:
         # ONE slot snapshot per batch: the whole batch — and every RESULT in
         # it — is served from a single (version, params) pair; a concurrent
         # publish simply lands in the next batch. This is the entire
         # hot-swap protocol: no lock spans the jit, no request can drop.
-        pub = self.slot.snapshot()
-        s = self.img_size
-        imgs = np.zeros((self.batch, s, s, 3), np.float32)
-        for i, (_, _, img) in enumerate(items):
-            imgs[i] = img
-        pred = self._program(pub.params, jnp.asarray(imgs))
-        pred = jax.tree.map(np.asarray, pred)
-        tier = freshness_tier(
-            max(0, self.latest_version() - pub.version),
-            max(0.0, self.slot.now() - pub.published_t),
-            self.fed,
-        )
+        with telemetry.span("serve.pad", id=bid):
+            pub = self.slot.snapshot()
+            s = self.img_size
+            imgs = np.zeros((self.batch, s, s, 3), np.float32)
+            for i, it in enumerate(items):
+                imgs[i] = it.img
+        with telemetry.span("serve.h2d", id=bid):
+            # rebinding frees the NumPy batch now: held to the batch's end,
+            # each batch of a warm process faults in a fresh buffer
+            # (DESIGN.md §18)
+            imgs = jnp.asarray(imgs)
+        with telemetry.span("serve.dispatch", id=bid):
+            pred = self._program(pub.params, imgs)
+        with telemetry.span("serve.device_wait", id=bid):
+            pred = jax.tree.map(np.asarray, pred)
+        with telemetry.span("serve.decode", id=bid):
+            tier = freshness_tier(
+                max(0, self.latest_version() - pub.version),
+                max(0.0, self.slot.now() - pub.published_t),
+                self.fed,
+            )
+            frames = [wire.pack_result(it.rid, pub.version, TIER_CODES[tier], decode_result(pred, i))
+                      for i, it in enumerate(items)]
         # Count the results BEFORE sending them: a client that has received
         # its RESULT must never observe in_flight > 0 for that request, so
         # the quiesce check (in_flight == 0 once every response arrived) is
@@ -467,10 +515,10 @@ class InferenceService:
             self.stats.batches += 1
             self.stats.occupancy_sum += len(items)
             self.stats.results += len(items)
-        for i, (sock, rid, _) in enumerate(items):
-            self._send(sock, wire.pack_result(
-                rid, pub.version, TIER_CODES[tier], decode_result(pred, i)
-            ))
+        with telemetry.span("serve.send", id=bid):
+            for it, frame in zip(items, frames):
+                self._send(it.sock, frame)
+                telemetry.add("serve.request", it.first_ns, telemetry.now_ns(), id=it.sid, seq=it.seq)
 
 
 # -- the consumer half -------------------------------------------------------
