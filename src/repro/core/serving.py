@@ -43,7 +43,7 @@ import queue
 import socket
 import threading
 import time
-from typing import Any, Callable, NamedTuple
+from typing import Any, Callable
 
 import numpy as np
 
@@ -205,6 +205,12 @@ def detection_program(cfg, max_detections: int) -> Callable:
     return program
 
 
+@jax.jit
+def stack_frames(*frames):
+    """One ``(len(frames), H, W, 3)`` batch from frames already on the device."""
+    return jnp.stack(frames)
+
+
 def decode_result(pred: dict, i: int) -> list[tuple[int, float, tuple]]:
     """Slot ``i`` of a program output -> the RESULT frame's detection list
     (kept slots only, score order preserved)."""
@@ -220,14 +226,25 @@ def decode_result(pred: dict, i: int) -> list[tuple[int, float, tuple]]:
 
 # -- the service -------------------------------------------------------------
 
-class _Pending(NamedTuple):
+# Frames a service keeps staged on the device ahead of its batches, in
+# batches of ``serve_batch``: at 8 slots of 416 px f32, 32 frames (82 MB in
+# the v5e's tiled layout, which pads each 416-wide row to 512).
+# It bounds the device memory a backlog can take; a frame past it waits on
+# the host and crosses when its batch closes.
+STAGE_AHEAD_BATCHES = 4
+
+
+@dataclasses.dataclass(slots=True)
+class _Pending:
     """An accepted INFER waiting for the batcher: the reply's socket and
-    wire id, the image, the service's request id and ``serve.request`` span
-    number, and when its first byte arrived and when it was enqueued."""
+    wire id, the frame (a device array once staged, else the host array;
+    None once its batch took it), the service's request id and
+    ``serve.request`` span number, and when its first byte arrived and when
+    it was enqueued."""
 
     sock: socket.socket
     rid: int
-    img: np.ndarray
+    frame: jax.Array | np.ndarray | None
     sid: int
     seq: int
     first_ns: int
@@ -273,22 +290,26 @@ class ServeStats:
 class InferenceService:
     """Socket-served batched detection over the wire framing.
 
-    Reader threads parse INFER frames and enqueue each with the service's
-    own request id; ONE batcher thread (the only jit caller) collects up to
-    ``fed.serve_batch`` requests per launch — the first request opens the
-    batch, then the batcher lingers ``fed.serve_max_wait_s`` for the rest
-    of the slots — zero-pads to the fixed batch, snapshots the `ModelSlot`
-    once, runs the cached program, and answers each request with its
-    slot's detections + the snapshot's round version + the freshness tier.
+    Reader threads parse INFER frames, put each on the device (while fewer
+    than ``STAGE_AHEAD_BATCHES`` batches' worth wait there) and enqueue it
+    with the service's own request id; ONE batcher thread (the only jit
+    caller) collects up to ``fed.serve_batch`` requests per launch — the
+    first request opens the batch, then the batcher lingers
+    ``fed.serve_max_wait_s`` for the rest of the slots — stacks their
+    frames on the device with one shared zero frame in each empty slot,
+    snapshots the `ModelSlot` once, runs the cached program, and answers
+    each request with its slot's detections + the snapshot's round version
+    + the freshness tier.
     STATUS frames are answered from the reader (they never touch the jit)
     through the same :func:`model_status` evaluator the monitor uses.
     Every request and batch leaves its ``serve.*`` spans in `telemetry`'s
     ring, under the request id and a batch id.
 
-    A batch that raises (a program the compiler refused, say) fails loudly:
-    the first exception is kept in ``error``, that batch's and every later
-    request is answered with an ERROR frame carrying its message (the
-    client raises it), and :meth:`stop` re-raises it.
+    A batch or a frame's staging that raises (a program the compiler
+    refused, say) fails loudly: the first exception is kept in ``error``,
+    that batch's or request's and every later request is answered with an
+    ERROR frame carrying its message (the client raises it), and
+    :meth:`stop` re-raises it.
 
     ``latest_version``: callable returning the newest landed training
     round (e.g. ``lambda: engine.version``) — what rounds-behind is
@@ -309,6 +330,8 @@ class InferenceService:
         self.max_detections = int(max_detections) or fed.serve_max_detections
         self._latest_version = latest_version
         self._program = detection_program(cfg, self.max_detections)
+        self._zero = jnp.zeros((self.img_size, self.img_size, 3), jnp.float32)  # every empty slot
+        self._staged = 0  # frames on the device that no batch has taken yet
         self.stats = ServeStats()
         self._stats_lock = threading.Lock()
         self._q: queue.Queue = queue.Queue()
@@ -316,7 +339,7 @@ class InferenceService:
         self._batch_ids = itertools.count()
         self._send_locks: dict[int, threading.Lock] = {}
         self._stopping = threading.Event()
-        self.error: Exception | None = None  # first batch failure, re-raised by stop()
+        self.error: Exception | None = None  # first batch or staging failure, re-raised by stop()
         self._listener = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
         self._listener.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
         self._listener.bind((host, port))
@@ -426,9 +449,17 @@ class InferenceService:
                     with self._stats_lock:
                         self.stats.requests += 1
                     sid, req_seq, recv_seq = next(self._request_ids), telemetry.reserve(), telemetry.reserve()
-                    enq_ns = telemetry.now_ns()
-                    telemetry.add("serve.parse", parse_ns, enq_ns, id=sid, parent=recv_seq)
-                    self._q.put(_Pending(sock, rid, img, sid, req_seq, frame_ns, enq_ns))
+                    stage_ns = telemetry.now_ns()
+                    telemetry.add("serve.parse", parse_ns, stage_ns, id=sid, parent=recv_seq)
+                    try:
+                        img = self._stage(img)  # rebinding lets go of the host frame
+                    except Exception as e:  # noqa: BLE001 — surfaced, never swallowed
+                        self._fail(e, [(sock, rid)])
+                    else:
+                        enq_ns = telemetry.now_ns()
+                        if isinstance(img, jax.Array):
+                            telemetry.add("serve.stage", stage_ns, enq_ns, id=sid, parent=recv_seq)
+                        self._q.put(_Pending(sock, rid, img, sid, req_seq, frame_ns, enq_ns))
                     parse_ns = telemetry.now_ns()
                     telemetry.add("serve.recv", frame_ns, parse_ns, id=sid, parent=req_seq, seq=recv_seq)
                 elif ftype == wire.STATUS:
@@ -438,6 +469,29 @@ class InferenceService:
                 # anything else on a serving socket is ignored (the federation
                 # frame types belong to the WireServer's port)
                 frame_ns = got_ns  # a later frame's first byte came in this chunk at the earliest
+
+    def _stage(self, img: np.ndarray) -> jax.Array | np.ndarray:
+        """Start ``img``'s copy to the device, unless ``STAGE_AHEAD_BATCHES``
+        batches' worth of frames already wait there: then the host frame."""
+        with self._stats_lock:
+            if self._staged >= STAGE_AHEAD_BATCHES * self.batch:
+                return img
+            self._staged += 1
+        try:
+            return jax.device_put(img)
+        except BaseException:
+            with self._stats_lock:
+                self._staged -= 1
+            raise
+
+    def _take_frames(self, items: list) -> list:
+        """The batch's frames, which its requests then no longer hold."""
+        frames = [it.frame for it in items]
+        for it in items:
+            it.frame = None
+        with self._stats_lock:
+            self._staged -= sum(isinstance(f, jax.Array) for f in frames)
+        return frames
 
     # -- batcher (the only jit caller) ---------------------------------------
 
@@ -463,21 +517,30 @@ class InferenceService:
                 closed_ns = telemetry.now_ns()
                 for it in items:
                     telemetry.add("serve.queue", it.enq_ns, closed_ns, id=it.sid, parent=it.seq)
-                if self.error is None:
+                ready = tuple(it.sid for it in items if isinstance(it.frame, jax.Array) and it.frame.is_ready())
+                telemetry.add("serve.staged", closed_ns, closed_ns, id=batch.id, parent=batch.seq, ids=ready)
+                telemetry.count("serve.frames", len(items))
+                telemetry.count("serve.frames_ready", len(ready))
+                error = self.error
+                if error is None:
                     try:
                         self._run_batch(items, batch.id)
                         continue
                     except Exception as e:  # noqa: BLE001 — surfaced, never swallowed
-                        self.error = e
-                self._fail_batch(items)
+                        error = e
+                self._take_frames(items)
+                self._fail(error, [(it.sock, it.rid) for it in items])
 
-    def _fail_batch(self, items: list) -> None:
-        """Answer every request of a batch with the service's error."""
-        msg = f"{type(self.error).__name__}: {self.error}"
+    def _fail(self, error: Exception | None, requests: list) -> None:
+        """Keep ``error`` if it is the service's first, then answer each
+        ``(socket, wire id)`` of ``requests`` with the service's error."""
         with self._stats_lock:
-            self.stats.failed += len(items)
-        for it in items:
-            self._send(it.sock, wire.pack_error(it.rid, msg))
+            if self.error is None:
+                self.error = error
+            self.stats.failed += len(requests)
+        msg = f"{type(self.error).__name__}: {self.error}"
+        for sock, rid in requests:
+            self._send(sock, wire.pack_error(rid, msg))
 
     def _run_batch(self, items: list, bid: int) -> None:
         # ONE slot snapshot per batch: the whole batch — and every RESULT in
@@ -486,15 +549,12 @@ class InferenceService:
         # hot-swap protocol: no lock spans the jit, no request can drop.
         with telemetry.span("serve.pad", id=bid):
             pub = self.slot.snapshot()
-            s = self.img_size
-            imgs = np.zeros((self.batch, s, s, 3), np.float32)
-            for i, it in enumerate(items):
-                imgs[i] = it.img
+            frames = self._take_frames(items)
+            frames += [self._zero] * (self.batch - len(frames))
         with telemetry.span("serve.h2d", id=bid):
-            # rebinding frees the NumPy batch now: held to the batch's end,
-            # each batch of a warm process faults in a fresh buffer
-            # (DESIGN.md §18)
-            imgs = jnp.asarray(imgs)
+            # a frame that came past the stage-ahead bound crosses now
+            imgs = stack_frames(*(f if isinstance(f, jax.Array) else jax.device_put(f) for f in frames))
+            del frames
         with telemetry.span("serve.dispatch", id=bid):
             pred = self._program(pub.params, imgs)
         with telemetry.span("serve.device_wait", id=bid):

@@ -13,6 +13,10 @@ service end to end, and the version contracts against the async engine:
     shares the fixed-slot batch with 7 other images or rides alone with 7
     zero-padded slots — per-slot decode is a function of that slot alone,
     and the socket path returns exactly the direct program's bits.
+  - frames cross to the device as they arrive: a batch of occupancy k
+    stages k frames and none for its padding; frames past the stage-ahead
+    bound wait on the host and cross at close, with the same bits; a
+    staging failure is answered with an ERROR frame and re-raised by stop().
   - hot swap under load drops zero requests and post-swap responses carry
     the new round version.
   - the served version ALWAYS equals the engine's landed round version:
@@ -36,7 +40,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_arch
-from repro.core import monitor, serving
+from repro.core import monitor, serving, telemetry
 from repro.core import rounds as R
 from repro.core.simclock import SimClock
 from repro.core.transport import harness, replay, wire
@@ -354,6 +358,140 @@ def test_failing_program_surfaces_its_error():
             with pytest.raises(serving.ServiceError, match="injected"):
                 client.infer(scenes(1, seed=1)[0])
         assert svc.stats.failed == 2 and svc.stats.in_flight == 0
+    finally:
+        with pytest.raises(RuntimeError, match="injected"):
+            svc.stop()
+
+
+def _warm(svc, img) -> None:
+    with serving.InferenceClient(svc.host, svc.port) as warm:
+        warm.infer(img)  # compiles outside the batch under test
+
+
+def _one_batch(svc, imgs) -> list:
+    """Send ``imgs`` in one write, so that they share one batch under a long
+    linger; their results in order."""
+    with serving.InferenceClient(svc.host, svc.port) as client:
+        client.sock.sendall(b"".join(wire.pack_infer(i, img) for i, img in enumerate(imgs)))
+        got = sorted((client.recv_result() for _ in imgs), key=lambda r: r.request_id)
+    return got
+
+
+def test_batch_stages_its_frames_and_none_for_padding(monkeypatch):
+    cfg, fed, slot, svc = serve_ctx(tiny_fed(serve_batch=4, serve_max_wait_s=1.0))
+    puts = []
+    put = jax.device_put
+    monkeypatch.setattr(jax, "device_put", lambda x, *a, **kw: puts.append(np.shape(x)) or put(x, *a, **kw))
+    try:
+        imgs = scenes(3, seed=11)
+        _warm(svc, imgs[0])
+        puts.clear()
+        before, mark = telemetry.counters(), telemetry.reserve()
+        _one_batch(svc, imgs)
+    finally:
+        svc.stop()
+    assert puts == [(IMG, IMG, 3)] * 3  # one put a request, none for the padding slot
+    # the ring is per process: other tests' 3-request batches sit before ``mark``
+    (batch,) = [b for b in telemetry.spans("serve.batch") if b.seq > mark]
+    assert {s.id for s in telemetry.spans("serve.stage") if s.seq > mark} == set(batch.ids)
+    after = telemetry.counters()
+    assert after["serve.frames"] - before.get("serve.frames", 0) == 3
+    assert 0 <= after["serve.frames_ready"] - before.get("serve.frames_ready", 0) <= 3
+    assert svc._staged == 0  # every staged frame was taken by its batch
+
+
+@pytest.mark.parametrize("stage_ahead", [0, serving.STAGE_AHEAD_BATCHES])
+def test_staged_and_host_frames_serve_the_zero_padded_program_bitwise(monkeypatch, stage_ahead):
+    """With the stage-ahead bound at 0 every frame crosses when its batch
+    closes; either way a batch of 3 in 4 slots answers with the bits of the
+    direct program on a zero-padded host batch."""
+    monkeypatch.setattr(serving, "STAGE_AHEAD_BATCHES", stage_ahead)
+    cfg, fed, slot, svc = serve_ctx(tiny_fed(serve_batch=4, serve_max_wait_s=1.0))
+    telemetry.reset()
+    try:
+        imgs = scenes(3, seed=12)
+        _warm(svc, imgs[0])
+        got = _one_batch(svc, imgs)
+    finally:
+        svc.stop()
+    pad = np.zeros((fed.serve_batch, IMG, IMG, 3), np.float32)
+    pad[:3] = imgs
+    prog = serving.detection_program(cfg, fed.serve_max_detections)
+    pred = jax.tree.map(np.asarray, prog(slot.snapshot().params, jnp.asarray(pad)))
+    assert [r.detections for r in got] == [serving.decode_result(pred, i) for i in range(3)]
+    assert len(telemetry.spans("serve.stage")) == (4 if stage_ahead else 0)  # the warm request too
+    if not stage_ahead:
+        assert [s.ids for s in telemetry.spans("serve.staged")] == [(), ()]
+
+
+def test_stage_ahead_bound_keeps_later_frames_on_the_host():
+    cfg, fed = tiny_cfg(), tiny_fed(serve_batch=2)
+    slot = serving.ModelSlot()
+    slot.publish(1, tiny_params(cfg))
+    svc = serving.InferenceService(cfg, fed, slot, img_size=IMG)  # never started: no batcher takes them
+    try:
+        limit = serving.STAGE_AHEAD_BATCHES * fed.serve_batch
+        frames = [svc._stage(img) for img in scenes(limit + 3, seed=13)]
+        assert [isinstance(f, jax.Array) for f in frames] == [True] * limit + [False] * 3
+        assert svc._staged == limit
+        items = [serving._Pending(None, i, f, i, 0, 0, 0) for i, f in enumerate(frames[:2])]
+        assert len(svc._take_frames(items)) == 2 and all(it.frame is None for it in items)
+        assert svc._staged == limit - 2
+        assert isinstance(svc._stage(scenes(1, seed=14)[0]), jax.Array)
+    finally:
+        svc.stop()
+
+
+def test_concurrent_readers_never_stage_past_the_bound():
+    """16 reader threads staging at once, switching every microsecond: the
+    bound holds exactly, and every frame past it stays on the host."""
+    cfg, fed = tiny_cfg(), tiny_fed(serve_batch=2)
+    slot = serving.ModelSlot()
+    slot.publish(1, tiny_params(cfg))
+    svc = serving.InferenceService(cfg, fed, slot, img_size=IMG)
+    img = scenes(1, seed=15)[0]
+    got, lock = [], threading.Lock()
+
+    def reader():
+        for _ in range(8):
+            frame = svc._stage(img)
+            with lock:
+                got.append(isinstance(frame, jax.Array))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=reader) for _ in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60.0)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+        svc.stop()
+    limit = serving.STAGE_AHEAD_BATCHES * fed.serve_batch
+    assert len(got) == 128 and sum(got) == limit == svc._staged
+
+
+def test_failing_stage_answers_with_an_error_frame(monkeypatch):
+    """A put that raises in the reader fails its request at once with the
+    server's message, later requests fail the same way, and stop()
+    re-raises; nothing reaches the batcher and nothing hangs."""
+    _, _, _, svc = serve_ctx()
+
+    def refused(x, *a, **kw):
+        raise RuntimeError("device_put refused: injected")
+
+    monkeypatch.setattr(jax, "device_put", refused)
+    try:
+        with serving.InferenceClient(svc.host, svc.port, timeout=10.0) as client:
+            with pytest.raises(serving.ServiceError, match="device_put refused"):
+                client.infer(scenes(1)[0])
+            with pytest.raises(serving.ServiceError, match="injected"):
+                client.infer(scenes(1, seed=1)[0])
+        assert svc.stats.failed == 2 and svc.stats.in_flight == 0 and svc.stats.batches == 0
+        assert svc._staged == 0
     finally:
         with pytest.raises(RuntimeError, match="injected"):
             svc.stop()

@@ -5,7 +5,8 @@ Pins:
   - the ring's bound and order, span nesting, self time as a span less its
     children, the window helpers the benchmark's readers use;
   - one 3-request batch over the socket: each request's spans share its id
-    and run recv -> queue -> the batch's children in order -> its send;
+    and run recv (parse, then stage) -> queue -> the batch's children in
+    order (its ``serve.staged`` mark at the close) -> its send;
   - `FLServer.run_round`'s ``fl.round`` with its children (``fl.device_wait``
     among them) and `RoundRecord.seconds` taken from it;
   - the compiled round carrying the four scope names in ``op_name``, in
@@ -167,21 +168,25 @@ def test_one_batch_spans_share_request_ids_in_order():
     assert batch.ids == (3, 4, 5)
     kids = telemetry.children()
     phases = [c.name for c in kids[batch.seq]]
-    assert phases == ["serve.linger", "serve.pad", "serve.h2d", "serve.dispatch", "serve.device_wait",
-                      "serve.decode", "serve.send"]
+    assert phases == ["serve.linger", "serve.staged", "serve.pad", "serve.h2d", "serve.dispatch",
+                      "serve.device_wait", "serve.decode", "serve.send"]
     steps = kids[batch.seq]
     assert all(a.end_ns <= b.start_ns for a, b in zip(steps, steps[1:]))
-    linger, send = steps[0], steps[-1]
+    linger, staged, send = steps[0], steps[1], steps[-1]
+    assert staged.id == batch.id and staged.ns == 0 and set(staged.ids) <= set(batch.ids)
     by = lambda name: {s.id: s for s in telemetry.spans(name) if s.id in batch.ids}
-    recv, parse, queue, request = (by(n) for n in ("serve.recv", "serve.parse", "serve.queue", "serve.request"))
+    recv, parse, stage, queue, request = (by(n) for n in ("serve.recv", "serve.parse", "serve.stage",
+                                                          "serve.queue", "serve.request"))
     for sid in batch.ids:
-        r, q, req = recv[sid], queue[sid], request[sid]
-        assert r.parent == req.seq and q.parent == req.seq and parse[sid].parent == r.seq
+        r, st, q, req = recv[sid], stage[sid], queue[sid], request[sid]
+        assert r.parent == req.seq and q.parent == req.seq and parse[sid].parent == r.seq and st.parent == r.seq
         assert req.start_ns == r.start_ns <= parse[sid].start_ns <= parse[sid].end_ns <= r.end_ns
+        assert parse[sid].end_ns <= st.start_ns <= st.end_ns <= q.start_ns
         assert r.start_ns <= q.start_ns <= q.end_ns <= steps[1].start_ns
+        assert q.end_ns == staged.start_ns
         assert q.end_ns >= linger.start_ns
         assert send.start_ns <= req.end_ns <= send.end_ns
-        assert all(s.ns >= 0 for s in (r, parse[sid], q, req))
+        assert all(s.ns >= 0 for s in (r, parse[sid], st, q, req))
 
 
 # --------------------------- the round ---------------------------------------
